@@ -75,28 +75,23 @@ def check(ok: bool, what: str):
 # ----------------------------------------------------------------------
 class Recorder:
     """Prints one JSON line per phase: wall seconds, the seconds JAX spent
-    tracing, lowering and compiling inside the phase, and the device's peak
-    memory so far."""
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
+    tracing, lowering and compiling inside the phase and the functions that
+    took most of them (the program's compile counter,
+    `repro.serving.spans.COMPILES`), and the device's peak memory so far."""
 
     def __init__(self):
-        self.compile_s = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
+        from repro.serving.spans import COMPILES
+        self.compiles = COMPILES
 
-    def _on(self, event: str, secs: float, **_):
-        if event in self.EVENTS:
-            self.compile_s += secs
+    def start(self) -> float:
+        return time.monotonic()
 
-    def start(self) -> tuple:
-        return time.perf_counter(), self.compile_s
-
-    def emit(self, phase: str, started: tuple, **fields):
-        t0, c0 = started
+    def emit(self, phase: str, started: float, **fields):
+        now = time.monotonic()
         stats = jax.devices()[0].memory_stats() or {}
-        rec = {"phase": phase, "wall_s": time.perf_counter() - t0,
-               "compile_s": self.compile_s - c0,
+        rec = {"phase": phase, "wall_s": now - started,
+               "compile_s": self.compiles.seconds_between(started, now),
+               "compiled": self.compiles.by_fun_between(started, now),
                "peak_bytes_in_use": stats.get("peak_bytes_in_use"), **fields}
         print(json.dumps(rec, default=str), flush=True)
 

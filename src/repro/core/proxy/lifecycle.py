@@ -31,6 +31,8 @@ class Request:
     prefill_instance: Optional[int] = None
     decode_instance: Optional[int] = None
     output_tokens: list = field(default_factory=list)
+    prefill_started: Optional[float] = None  # first picked by a prefill
+                                             # engine's round
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     n_retries: int = 0                 # straggler/failure re-dispatches
@@ -57,6 +59,12 @@ class Request:
         if self.first_token_time is None:
             return None
         return self.first_token_time - self.arrival
+
+    def prefill_wait(self) -> Optional[float]:
+        """The part of TTFT spent before any prefill work began."""
+        if self.prefill_started is None:
+            return None
+        return self.prefill_started - self.arrival
 
     def tpot(self) -> Optional[float]:
         if self.finish_time is None or self.first_token_time is None:

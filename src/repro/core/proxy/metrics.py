@@ -137,6 +137,7 @@ class MetricsAggregator:
             return {"n_done": 0, "qpm": 0.0, **self._reasons(),
                     **self._robustness(),
                     "ttft_mean": nan, "ttft_p99": nan,
+                    "prefill_wait_mean": nan,
                     "tpot_mean_ms": nan, "tpot_p99_ms": nan,
                     "e2e_mean": nan, "e2e_p99": nan,
                     "ott_tok_s": 0.0, "ttt_tok_s": 0.0,
@@ -144,6 +145,8 @@ class MetricsAggregator:
                     "kv_transfer_padded_bytes": self.kv_transfer_padded_bytes,
                     **self._sparsity(), **self._spec()}
         ttft = np.array([r.ttft() for r in self.done if r.ttft() is not None])
+        wait = np.array([r.prefill_wait() for r in self.done
+                         if r.prefill_wait() is not None])
         tpot = np.array([r.tpot() for r in self.done if r.tpot() is not None])
         e2e = np.array([r.e2e() for r in self.done])
         out_toks = sum(len(r.output_tokens) for r in self.done)
@@ -157,6 +160,9 @@ class MetricsAggregator:
             "qpm": 60.0 * len(self.done) / wall,
             "ttft_mean": float(ttft.mean()) if len(ttft) else float("nan"),
             "ttft_p99": pct(ttft, 99),
+            # the part of TTFT before any prefill work began
+            "prefill_wait_mean": (float(wait.mean()) if len(wait)
+                                  else float("nan")),
             "tpot_mean_ms": 1e3 * float(tpot.mean()) if len(tpot) else float("nan"),
             "tpot_p99_ms": 1e3 * pct(tpot, 99),
             "e2e_mean": float(e2e.mean()),

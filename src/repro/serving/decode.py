@@ -40,6 +40,7 @@ from repro.serving.kvpool import KVPool
 from repro.serving.placement import DevicePlacement
 from repro.serving.sampling import sample_tokens
 from repro.serving.sparsity import SparsityController
+from repro.serving.spans import span
 from repro.serving.spec import SpecConfig, SpecController
 from repro.serving.stats import drain_accumulator
 
@@ -76,7 +77,7 @@ class DecodeEngine:
     stats: dict = field(default_factory=lambda: {
         "steps": 0, "tokens": 0, "busy_s": 0.0, "kv_transfer_bytes": 0,
         "kv_transfer_bytes_padded": 0, "handoff_copy_bytes": 0,
-        "admits": 0, "preemptions": 0, "moe_counts": None,
+        "admits": 0, "preemptions": 0,
         "blocks_touched": 0, "blocks_shared": 0, "blocks_fresh": 0,
         "host_fetches": 0})
 
@@ -880,12 +881,14 @@ class DecodeEngine:
                 self.stats["blocks_fresh"] += len(grown)
             self.tokens_h[slot] = want
         self.stats["blocks_touched"] += touched
-        self._refresh_tables()
-        cache, self.state, packed = self._verify(
-            self.params, self._full_cache(), self.state, self.tables,
-            self._tbl_dev, jnp.asarray(drafts_h), jnp.asarray(dlen_h))
-        self._store_cache(cache)
-        packed_np = np.asarray(packed)     # the single per-step host fetch
+        with span("omni.decode.dispatch"):
+            self._refresh_tables()
+            cache, self.state, packed = self._verify(
+                self.params, self._full_cache(), self.state, self.tables,
+                self._tbl_dev, jnp.asarray(drafts_h), jnp.asarray(dlen_h))
+            self._store_cache(cache)
+        with span("omni.decode.fetch"):
+            packed_np = np.asarray(packed)  # the single per-step host fetch
         self.stats["host_fetches"] += 1
         out = {}
         ntok = 0
@@ -938,13 +941,15 @@ class DecodeEngine:
     def _step_base(self) -> dict[int, int]:
         """The non-speculative single-token step → {rid: next_token}."""
         t0 = time.monotonic()
-        if self.paged:
-            self._refresh_tables()
-        cache, self.state, nxt = self._step(
-            self.params, self._full_cache(), self.state, self.tables,
-            self._tbl_dev if self.paged else None)
-        self._store_cache(cache)
-        next_np = np.asarray(nxt)          # the single per-step host fetch
+        with span("omni.decode.dispatch"):
+            if self.paged:
+                self._refresh_tables()
+            cache, self.state, nxt = self._step(
+                self.params, self._full_cache(), self.state, self.tables,
+                self._tbl_dev if self.paged else None)
+            self._store_cache(cache)
+        with span("omni.decode.fetch"):
+            next_np = np.asarray(nxt)      # the single per-step host fetch
         self.stats["host_fetches"] += 1
         out = {}
         for slot, rid in list(self.slot_rid.items()):
@@ -995,11 +1000,7 @@ class DecodeEngine:
         """Fetch + reset the device-side expert activation window ([L_moe, E]
         np array, or None for non-MoE models). The only host sync for counts
         — call it at monitor ticks, not per step."""
-        out = drain_accumulator(self.state, "moe_counts")
-        if out is None:
-            return None
-        self.stats["moe_counts"] = out          # last fetched window (stats)
-        return out
+        return drain_accumulator(self.state, "moe_counts")
 
     def _preempt(self, rid: int) -> tuple:
         slot = self.rid_slot[rid]

@@ -42,6 +42,7 @@ from repro.serving.arena import BlockHandoff, KVArena, _bucket, _pow2_floor
 from repro.serving.kvpool import PrefixKVStore, _pytree_bytes
 from repro.serving.placement import DevicePlacement
 from repro.serving.sampling import sample_tokens
+from repro.serving.spans import span
 
 
 # ======================================================================
@@ -56,6 +57,7 @@ class PrefillTask:
     snap: int = 0                     # snapshot boundary (shared-prefix hint)
     params: SamplingParams = GREEDY   # first-token decoding config
     t_start: float = 0.0
+    prefill_started: Optional[float] = None   # first picked in a round
     compute_s: float = 0.0            # pure prefill compute (excl. queue wait)
     handoff: object = None            # BlockHandoff once finished (paged)
 
@@ -73,6 +75,7 @@ class PrefillResult:
     reused: int
     elapsed_s: float                  # prefill compute time (EWMA batch time)
     t_done: float = 0.0               # wall time the first token materialized
+    prefill_started: Optional[float] = None   # wall time of the first pick
 
 
 @dataclass
@@ -248,6 +251,22 @@ class PrefillEngine:
         self.store.put(task.prompt[:n], priv, task.logits, blocks=blocks,
                        nbytes=nbytes)
 
+    def _store_put(self, task: PrefillTask, n: int, copy: bool) -> None:
+        """Publish the first `n` tokens of a task as a store entry; `copy`
+        snapshots the task's own state, which its later chunks donate."""
+        with span("omni.prefill.store", rid=task.rid, tokens=n):
+            if self.paged:
+                self._store_put_paged(task, n, copy_private=copy)
+            else:
+                # prefix-length entry (sizing fix): the full-attention KV
+                # sliced to the boundary instead of pinning a max_len copy
+                self.store.put(
+                    task.prompt[:n],
+                    self._resize_full_attn(
+                        task.cache, min(_bucket(n, lo=8), self.max_len),
+                        copy_rest=copy),
+                    task.logits)
+
     def _release_result(self, rec: PrefillResult) -> None:
         """Drop an undelivered result (supersede/abort): a paged handoff
         still owns pool blocks that nobody will ever admit."""
@@ -292,7 +311,8 @@ class PrefillEngine:
         if (self.chunked and self.allow_partial_reuse
                 and 8 <= prefix_hint < len(task.prompt)):
             task.snap = prefix_hint
-        self._try_resume(task)
+        with span("omni.prefill.resume", rid=rid):
+            self._try_resume(task)
         self.queue.append(task)
 
     def _try_resume(self, task: PrefillTask) -> None:
@@ -417,10 +437,13 @@ class PrefillEngine:
                 break
             task = (min(cands, key=lambda t: t.remaining)
                     if self.chunked else cands[0])
+            if task.prefill_started is None:
+                task.prefill_started = time.monotonic()
             if task.cursor == 0:
                 # entries stored since enqueue (e.g. a queued sharer's
                 # snapshot) are visible to tasks that have not started
-                self._try_resume(task)
+                with span("omni.prefill.resume", rid=task.rid):
+                    self._try_resume(task)
             if task.remaining > 0:
                 ran = (self._run_chunk(task, min(budget, self.chunk))
                        if self.chunked else self._run_full(task))
@@ -441,9 +464,22 @@ class PrefillEngine:
         cl = min(self.chunk, task.remaining, max(budget, 1))
         if task.cursor < task.snap:
             cl = min(cl, task.snap - task.cursor)   # land on the boundary
+        with span("omni.prefill.chunk", rid=task.rid, tokens=cl):
+            if not self._dispatch_chunk(task, cl):
+                return 0
+        self._note_peak(task)
+        if task.cursor == task.snap and \
+                self.store.lookup(task.prompt[:task.snap])[0] != task.snap:
+            self._store_put(task, task.snap, copy=True)
+        task.compute_s += time.monotonic() - t0
+        return cl
+
+    def _dispatch_chunk(self, task: PrefillTask, cl: int) -> bool:
+        """Reserve blocks for `cl` tokens and enqueue their chunk program;
+        → False where the pool could not grow (the task defers)."""
         if self.paged and not self._grow_blocks(task, cl):
             self.stats["defers"] += 1
-            return 0
+            return False
         if task.cache is None:
             task.cache = (alloc_prefill_private_cache(
                 self.lm.cfg, self.lm.mesh, self.lm.plan, self.max_len)
@@ -474,25 +510,7 @@ class PrefillEngine:
         task.cursor += cl
         self.stats["tokens"] += cl
         self.stats["chunks"] += 1
-        self._note_peak(task)
-        if task.cursor == task.snap:
-            shared = task.prompt[:task.snap]
-            if self.store.lookup(shared)[0] != task.snap:
-                if self.paged:
-                    self._store_put_paged(task, task.snap, copy_private=True)
-                else:
-                    # prefix-length snapshot (sizing fix): slice the
-                    # full-attention KV to the boundary instead of pinning
-                    # a max_len copy
-                    self.store.put(
-                        shared,
-                        self._resize_full_attn(
-                            task.cache,
-                            min(_bucket(task.snap, lo=8), self.max_len),
-                            copy_rest=True),
-                        task.logits)
-        task.compute_s += time.monotonic() - t0
-        return cl
+        return True
 
     def _run_full(self, task: PrefillTask) -> int:
         t0 = time.monotonic()
@@ -502,8 +520,9 @@ class PrefillEngine:
         # an unchunked engine
         pad = min(_bucket(S, lo=8), self.max_len) - S
         toks = jnp.asarray([list(task.prompt) + [0] * pad], jnp.int32)
-        task.cache, task.logits = self._fn(self.params, toks, jnp.int32(S),
-                                           self.tables)
+        with span("omni.prefill.chunk", rid=task.rid, tokens=S):
+            task.cache, task.logits = self._fn(self.params, toks,
+                                               jnp.int32(S), self.tables)
         task.cursor = S
         self.stats["tokens"] += S
         self._note_peak(task)
@@ -522,14 +541,7 @@ class PrefillEngine:
             self.stats["cache_hits"] += 1
         else:
             self.stats["prefills"] += 1
-            if self.paged:
-                self._store_put_paged(task, L, copy_private=False)
-            else:
-                self.store.put(
-                    task.prompt,
-                    self._resize_full_attn(
-                        task.cache, min(_bucket(L, lo=8), self.max_len)),
-                    task.logits)
+            self._store_put(task, L, copy=False)
         if self.paged:
             pool, key = self.arena.pool, self._pf_key(task.rid)
             # class-level counter: several engines share one pool (arena),
@@ -542,14 +554,16 @@ class PrefillEngine:
         return task
 
     def _emit(self, tasks: list) -> list[PrefillResult]:
-        toks = self.sample_first([t.logits for t in tasks],
-                                 [t.params for t in tasks],
-                                 [t.rid for t in tasks],
-                                 [len(t.prompt) for t in tasks])
+        with span("omni.prefill.sample", n=len(tasks)):
+            toks = self.sample_first([t.logits for t in tasks],
+                                     [t.params for t in tasks],
+                                     [t.rid for t in tasks],
+                                     [len(t.prompt) for t in tasks])
         t_done = time.monotonic()
         return [PrefillResult(t.rid, t.handoff if t.handoff is not None
                               else t.cache, int(tok), len(t.prompt),
-                              t.reused, t.compute_s, t_done)
+                              t.reused, t.compute_s, t_done,
+                              t.prefill_started)
                 for t, tok in zip(tasks, toks)]
 
     def sample_first(self, logits_list, params_list, rids, folds

@@ -48,6 +48,7 @@ from repro.serving.decode import DecodeEngine
 from repro.serving.placement import DevicePlacement
 from repro.serving.prefill import PrefillEngine
 from repro.serving.quant import QuantConfig, QuantController
+from repro.serving.spans import span
 from repro.serving.spec import SpecConfig
 
 
@@ -257,20 +258,22 @@ class Server:
         this step, plus finish records (finish_reason in {stop, length})
         and abort notifications."""
         now = time.monotonic() if now is None else now
-        if self.faults is not None:
-            # fire scheduled faults (and run their recovery) BEFORE this
-            # step's engine rounds: no token is ever computed from corrupt
-            # or lost KV, which is what makes completed outputs bit-identical
-            # to the fault-free run
-            self.faults.on_step(self, self._step_count, now)
-        if self.kv_arena is not None:
-            self._sweep_orphan_handoffs()
-        self._drain_actions(now)
-        self._sweep_failed(now)
-        self._prefill_round()
-        self._decode_round()
-        self._watchdog(now)
-        return self._flush_outputs()
+        with span("omni.step"):
+            if self.faults is not None:
+                # fire scheduled faults (and run their recovery) BEFORE
+                # this step's engine rounds: no token is ever computed from
+                # corrupt or lost KV, which is what makes completed outputs
+                # bit-identical to the fault-free run
+                self.faults.on_step(self, self._step_count, now)
+            if self.kv_arena is not None:
+                self._sweep_orphan_handoffs()
+            with span("omni.proxy.tick"):
+                self._drain_actions(now)
+            self._sweep_failed(now)
+            self._prefill_round()
+            self._decode_round()
+            self._watchdog(now)
+            return self._flush_outputs()
 
     def abort(self, rid: int, now: Optional[float] = None) -> bool:
         """Cancel a request wherever it lives: proxy pools, prefill queues,
@@ -577,7 +580,8 @@ class Server:
                 live.append(r)
             t0 = eng.stats["kv_transfer_bytes"]
             p0 = eng.stats["kv_transfer_bytes_padded"]
-            granted = eng.admit_batch(items)
+            with span("omni.decode.admit"):
+                granted = eng.admit_batch(items)
             self.metrics.note_kv_transfer(
                 eng.stats["kv_transfer_bytes"] - t0,
                 eng.stats["kv_transfer_bytes_padded"] - p0)
@@ -604,29 +608,36 @@ class Server:
                 continue
             if not eng.has_work():
                 continue
-            for rec in eng.step(budget):
-                req = self.proxy.inflight.get(rec.rid)
-                tnow = time.monotonic()
-                if req is None or req.prefill_instance != iid:
-                    # stale result for a re-dispatched rid
-                    self._release_handoff(rec.cache)
-                    continue
-                self.proxy.on_prefill_done(req, tnow, batch_time=rec.elapsed_s)
-                # the first token materialized inside the engine round, not
-                # when this bookkeeping runs
-                self.proxy.on_first_token(req, rec.t_done or tnow)
-                reason = self._note_token(req, rec.first_token)
-                if reason:
-                    # stop token / max_tokens=1 at the FIRST token: retire
-                    # without ever admitting to decode (the never-admitted
-                    # handoff's arena blocks are released here)
-                    self._release_handoff(rec.cache)
-                    self.proxy.on_early_finish(req, tnow)
-                    self._record_finish(req, reason)
-                else:
-                    self._pending_kv[req.rid] = (rec.cache, rec.first_token,
-                                                 rec.prompt_len, rec.reused,
-                                                 req.tokens, req.sampling)
+            with span("omni.prefill.round"):
+                self._prefill_results(iid, eng.step(budget))
+
+    def _prefill_results(self, iid: int, recs: list):
+        """Hand one prefill engine's finished prompts to the proxy."""
+        for rec in recs:
+            req = self.proxy.inflight.get(rec.rid)
+            tnow = time.monotonic()
+            if req is None or req.prefill_instance != iid:
+                # stale result for a re-dispatched rid
+                self._release_handoff(rec.cache)
+                continue
+            if req.prefill_started is None:
+                req.prefill_started = rec.prefill_started
+            self.proxy.on_prefill_done(req, tnow, batch_time=rec.elapsed_s)
+            # the first token materialized inside the engine round, not
+            # when this bookkeeping runs
+            self.proxy.on_first_token(req, rec.t_done or tnow)
+            reason = self._note_token(req, rec.first_token)
+            if reason:
+                # stop token / max_tokens=1 at the FIRST token: retire
+                # without ever admitting to decode (the never-admitted
+                # handoff's arena blocks are released here)
+                self._release_handoff(rec.cache)
+                self.proxy.on_early_finish(req, tnow)
+                self._record_finish(req, reason)
+            else:
+                self._pending_kv[req.rid] = (rec.cache, rec.first_token,
+                                             rec.prompt_len, rec.reused,
+                                             req.tokens, req.sampling)
 
     def _decode_round(self):
         for iid, eng in enumerate(self.decodes):
@@ -635,43 +646,48 @@ class Server:
                     eng.release(rid)             # proxy re-routes the reqs
                 eng.preempted.clear()
                 continue
-            toks = eng.step()
-            now = time.monotonic()
-            finished = set()
-            for rid, tok in toks.items():
-                req = self.proxy.inflight.get(rid)
-                if req is None or req.decode_instance != iid:
-                    eng.release(rid)             # done or re-routed elsewhere
-                    finished.add(rid)
-                    continue
-                # a speculating engine emits a LIST per slot (≥ 1 token per
-                # verify step); note each in order and stop at the first
-                # finish reason — tokens past a mid-window stop are never
-                # recorded or streamed, exactly as if decoded one at a time
-                seq = tok if isinstance(tok, (list, tuple)) else (tok,)
-                reason = None
-                for t in seq:
-                    reason = self._note_token(req, t)
-                    if reason:
-                        break
-                if reason:
-                    finished.add(rid)
-                    eng.release(rid)
-                    self.proxy.on_decode_done(req, now,
-                                              batch_time=eng.stats["busy_s"] /
-                                              max(eng.stats["steps"], 1))
-                    self._record_finish(req, reason)
-            for rid, cache_one, tok, pos in eng.preempted:
-                req = self.proxy.inflight.get(rid)
-                if rid in finished or req is None:
-                    continue
-                self._pending_kv[rid] = (cache_one, tok, pos, 0, req.tokens,
-                                         req.sampling)
-                self.proxy.on_decode_preempt(req, now)
-            eng.preempted.clear()
+            with span("omni.decode.round"):
+                self._decode_turn(iid, eng)
         self._step_count += 1
         self._maybe_placement_tick()
         self._maybe_sparsity_tick()
+
+    def _decode_turn(self, iid: int, eng: DecodeEngine):
+        """One decode engine's step and the bookkeeping of its tokens."""
+        toks = eng.step()
+        now = time.monotonic()
+        finished = set()
+        for rid, tok in toks.items():
+            req = self.proxy.inflight.get(rid)
+            if req is None or req.decode_instance != iid:
+                eng.release(rid)             # done or re-routed elsewhere
+                finished.add(rid)
+                continue
+            # a speculating engine emits a LIST per slot (≥ 1 token per
+            # verify step); note each in order and stop at the first
+            # finish reason — tokens past a mid-window stop are never
+            # recorded or streamed, exactly as if decoded one at a time
+            seq = tok if isinstance(tok, (list, tuple)) else (tok,)
+            reason = None
+            for t in seq:
+                reason = self._note_token(req, t)
+                if reason:
+                    break
+            if reason:
+                finished.add(rid)
+                eng.release(rid)
+                self.proxy.on_decode_done(req, now,
+                                          batch_time=eng.stats["busy_s"] /
+                                          max(eng.stats["steps"], 1))
+                self._record_finish(req, reason)
+        for rid, cache_one, tok, pos in eng.preempted:
+            req = self.proxy.inflight.get(rid)
+            if rid in finished or req is None:
+                continue
+            self._pending_kv[rid] = (cache_one, tok, pos, 0, req.tokens,
+                                     req.sampling)
+            self.proxy.on_decode_preempt(req, now)
+        eng.preempted.clear()
 
     def _maybe_sparsity_tick(self):
         """Drain the decode engines' device-side online-sparsity windows
@@ -682,15 +698,19 @@ class Server:
         per sparse engine; no-op when online sparsity is off."""
         if self._step_count % max(self.scfg.placement_interval, 1) != 0:
             return
-        for eng in self.decodes:
-            if eng.sparsity is not None:
-                sp = eng.take_sparsity_stats()
-                if sp is not None:
-                    self.metrics.note_sparsity(*sp)
-            if eng.spec_ctl is not None:
-                v = eng.take_spec_stats()
-                if v is not None:
-                    self.metrics.note_spec(*v)
+        if all(e.sparsity is None and e.spec_ctl is None
+               for e in self.decodes):
+            return
+        with span("omni.monitor"):
+            for eng in self.decodes:
+                if eng.sparsity is not None:
+                    sp = eng.take_sparsity_stats()
+                    if sp is not None:
+                        self.metrics.note_sparsity(*sp)
+                if eng.spec_ctl is not None:
+                    v = eng.take_spec_stats()
+                    if v is not None:
+                        self.metrics.note_spec(*v)
 
     # ---- OmniPlacement closed loop -----------------------------------
     def _maybe_placement_tick(self):
@@ -699,16 +719,18 @@ class Server:
         if (self.placement_sched is None or
                 self._step_count % max(self.scfg.placement_interval, 1) != 0):
             return
-        counts = None
-        for eng in self.decodes:
-            c = eng.take_moe_counts()           # fetch + reset the window
-            if c is not None:
-                counts = c if counts is None else counts + c
-        if counts is None:
-            return
-        plans = self.placement_sched.step(counts.sum(axis=0, keepdims=True))
-        if plans:
-            self._apply_migration(plans[0])
+        with span("omni.monitor"):
+            counts = None
+            for eng in self.decodes:
+                c = eng.take_moe_counts()       # fetch + reset the window
+                if c is not None:
+                    counts = c if counts is None else counts + c
+            if counts is None:
+                return
+            plans = self.placement_sched.step(counts.sum(axis=0,
+                                                         keepdims=True))
+            if plans:
+                self._apply_migration(plans[0])
 
     def _apply_migration(self, plan):
         """Rebuild MoE slot weights + tables for a new placement. The stack
@@ -774,11 +796,13 @@ class Server:
                 expired = True      # reported: n_done < n_requests
                 break
             while k < len(todo) and now - t_start >= todo[k][0]:
-                _, i, prompt, spec = todo[k]
+                offset, i, prompt, spec = todo[k]
                 params = spec if isinstance(spec, SamplingParams) else \
                     SamplingParams(max_tokens=int(spec))
                 try:
-                    self._submit(i, tuple(prompt), params, now)
+                    # arrival at its scheduled time, not when the loop got
+                    # round to it: a slow step's queueing counts in TTFT
+                    self._submit(i, tuple(prompt), params, t_start + offset)
                 except BackpressureError:
                     pass        # shed (counted in metrics.n_shed)
                 k += 1
