@@ -6,7 +6,7 @@ constructed through `DevicePlacement.donate_jit` lands in the placement's
 signatures (shape/dtype/sharding) at its first real call. Post-warmup —
 after a live `Server` has stepped real requests through the hot loops —
 `audit_placement` re-traces and re-lowers each called entry from those
-signatures (never touching live donated buffers) and asserts four
+signatures (never touching live donated buffers) and asserts these
 contracts on the artifact:
 
   · **purity** — no callback / debug / infeed / outfeed primitives
@@ -29,7 +29,16 @@ contracts on the artifact:
     GATHERED views (a handful of tabled blocks), so a float tensor with
     an int8 leaf's full [N, K, bs, h] trailing shape means the whole
     quantized arena was silently upcast to f32 in HBM — exactly the copy
-    the in-tile dequant contract exists to forbid.
+    the in-tile dequant contract exists to forbid;
+  · **arena-copy** — when a hot loop updates scan-stacked attention-cache
+    leaves (donated `cache["period"][i]["k"]` and its planes, [n_rep,
+    ...]), no `copy`, `broadcast` or `dynamic-slice` in the compiled
+    program (fused or not) may output a whole stacked leaf or a whole
+    one-layer slice of one, and no `dynamic-update-slice` may write one:
+    the layer scan carries the caches and each layer reads and writes
+    only its own rows in place (models/stack.py::stack_apply). These ops
+    are the signature of a cache scanned as xs/ys, sliced out and written
+    back per layer.
 
 Entries that were registered but never called (e.g. `_extract` when no
 preemption happened during warmup) are reported as skipped, not failed —
@@ -37,7 +46,10 @@ pass `require_called=True` to turn those into findings instead.
 """
 from __future__ import annotations
 
+import functools
+import re
 from dataclasses import dataclass, field
+from typing import Optional
 
 import jax
 import jax.extend.core as jex_core
@@ -54,7 +66,8 @@ F64_DTYPES = (np.dtype("float64"), np.dtype("complex128"))
 @dataclass
 class AuditFinding:
     entry: str
-    check: str          # purity | f64 | donation | out-shardings | trace
+    check: str  # purity | f64 | quant-upcast | donation | out-shardings
+    #             | arena-copy | trace
     detail: str
 
     def format(self) -> str:
@@ -131,7 +144,7 @@ def _entry_jaxpr(entry: HotLoopEntry):
 
 
 # ---------------------------------------------------------------------------
-# the four checks
+# the checks
 # ---------------------------------------------------------------------------
 
 def _check_purity(entry, jaxpr, report):
@@ -216,6 +229,114 @@ def _check_quant_upcast(entry, jaxpr, report):
                     f"HBM instead of dequantized in-tile"))
 
 
+# attention-cache leaf names: KV payloads, block summaries, scale planes
+KV_LEAVES = frozenset({"k", "v", "kmin", "kmax", "kmean",
+                       "kscale", "vscale", "ktok", "vtok"})
+COPY_OPS = frozenset({"copy", "broadcast", "dynamic-slice",
+                      "dynamic-update-slice"})
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(ROOT\s+)?%([\w.\-]+)\s*=\s*(\S+)\s+([\w\-]+)\(([^)]*)\)(.*)$")
+_HLO_DIMS = re.compile(r"^\w+\[([\d,]*)\]")
+_HLO_CALLS = re.compile(r"calls=%([\w.\-]+)")
+
+
+@dataclass
+class _Instr:
+    dims: Optional[tuple]       # None for tuple-shaped outputs
+    op: str
+    operands: tuple
+    root: bool
+    calls: Optional[str]
+
+
+def _hlo_computations(text) -> dict:
+    """{computation: {instruction: _Instr}} from an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), {})
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or cur is None:
+            continue
+        root, name, shape, op, args, rest = m.groups()
+        dm = _HLO_DIMS.match(shape)
+        dims = tuple(int(d) for d in dm.group(1).split(",") if d) \
+            if dm else None
+        operands = tuple(a.split("%")[-1].strip() for a in args.split(",")
+                         if "%" in a)
+        c = _HLO_CALLS.search(rest) if op == "fusion" else None
+        cur[name] = _Instr(dims, op, operands, bool(root),
+                           c.group(1) if c else None)
+    return comps
+
+
+def _materialized(comps, comp, name, fused=False):
+    """[(op, dims)] of the COPY_OPS whose result instruction `name` of
+    computation `comp` holds: the op itself, or for a fusion the op at its
+    root, looking through bitcasts and multi-output tuples. A
+    dynamic-update-slice counts by what it writes (its update operand),
+    every other op by its output."""
+    instrs = comps[comp]
+    ins = instrs[name]
+    if ins.op == "fusion" and not fused and ins.calls in comps:
+        root = next((n for n, i in comps[ins.calls].items() if i.root),
+                    None)
+        return _materialized(comps, ins.calls, root, True) if root else []
+    if fused and ins.op in ("bitcast", "tuple"):
+        return [hit for o in ins.operands if o in instrs
+                for hit in _materialized(comps, comp, o, True)]
+    if ins.op == "dynamic-update-slice" and len(ins.operands) > 1:
+        return [(ins.op, instrs.get(ins.operands[1], ins).dims)]
+    return [(ins.op, ins.dims)] if ins.op in COPY_OPS else []
+
+
+def _stacked_kv_leaves(entry) -> dict:
+    """{path: shape} of the scan-stacked attention-cache leaves among the
+    entry's donated arguments (the caches it updates): leaves named in
+    KV_LEAVES under a "period" key."""
+    out = {}
+    for i in entry.donate_argnums:
+        flat = jax.tree_util.tree_flatten_with_path(entry.abstract_args[i])
+        for path, leaf in flat[0]:
+            keys = [getattr(k, "key", None) for k in path]
+            shp = getattr(leaf, "shape", None)
+            if "period" in keys and keys[-1] in KV_LEAVES and shp:
+                out[f"arg {i}{jax.tree_util.keystr(path)}"] = tuple(shp)
+    return out
+
+
+def _check_arena_copy(entry, compiled, report):
+    """No whole-stack or whole-layer copy of a scan-stacked cache leaf in
+    the compiled program (see the module docstring). One layer [N, ...]
+    also matches with its unit layer axis kept, [1, N, ...]."""
+    leaves = _stacked_kv_leaves(entry)
+    if not leaves:
+        return
+    report._count("arena-copy")
+    banned = {}
+    for path, shp in leaves.items():
+        banned.setdefault(shp, f"all layers of {path}")
+        banned.setdefault(shp[1:], f"one layer of {path}")
+        banned.setdefault((1,) + shp[1:], f"one layer of {path}")
+    comps = _hlo_computations(compiled().as_text())
+    fused = {i.calls for instrs in comps.values() for i in instrs.values()}
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        for name in instrs:
+            for op, dims in _materialized(comps, comp, name):
+                hit = banned.get(dims)
+                if hit:
+                    report.findings.append(AuditFinding(
+                        entry.name, "arena-copy",
+                        f"`{name}` materializes a {op} of {list(dims)}, "
+                        f"{hit}: every call moves a whole layer or stack "
+                        f"of the cache, not just the rows it touches"))
+
+
 def _check_donation(entry, lowered, report):
     if not entry.donate_argnums:
         return
@@ -231,7 +352,7 @@ def _check_donation(entry, lowered, report):
             f"and every step pays a full copy"))
 
 
-def _check_out_shardings(entry, lowered, report):
+def _check_out_shardings(entry, lowered, compiled, report):
     """Compiled output shardings must equal the placement's own spec tree
     — only meaningful on a multi-device mesh (the 1-device choke point
     drops the pin by design)."""
@@ -239,7 +360,7 @@ def _check_out_shardings(entry, lowered, report):
     if entry.out_specs is None or pl.n_devices == 1:
         return
     report._count("out-shardings")
-    compiled = lowered.compile()
+    compiled = compiled()
     is_shard = lambda x: isinstance(x, jax.sharding.Sharding)  # noqa: E731
     actual = jax.tree.leaves(compiled.output_shardings, is_leaf=is_shard)
     expected = jax.tree.leaves(pl.tree_shardings(entry.out_specs),
@@ -279,8 +400,10 @@ def audit_entry(entry: HotLoopEntry, report: AuditReport) -> None:
     _check_purity(entry, jaxpr, report)
     _check_f64(entry, jaxpr, report)
     _check_quant_upcast(entry, jaxpr, report)
+    compiled = functools.cache(lowered.compile)
     _check_donation(entry, lowered, report)
-    _check_out_shardings(entry, lowered, report)
+    _check_out_shardings(entry, lowered, compiled, report)
+    _check_arena_copy(entry, compiled, report)
     report.audited.append(entry.name)
 
 

@@ -5,6 +5,11 @@ Layouts:
   k, v     [B, S, K, h]       (K = n_kv_heads, G = H//K)
   cache    [B, W, K, h]       per layer; W = allocated window
 
+Every cache helper below also takes a scan-stacked leaf [n_rep, ...] with
+`layer=i` (the layer scan's counter): reads gather and writes scatter at
+that layer's rows only, so the stack never slices a whole layer out or
+writes one back (`_at`).
+
 Sharding strategies (chosen per arch by the caller — see DESIGN.md):
   prefill: 'heads' → shard H over `model` (repeat-kv full-head layout)
            'qseq'  → shard q-chunk seq over `model` (few-head archs)
@@ -40,6 +45,32 @@ def arena_kv_part(n_kv: int, tp: int):
     dim — any rank can serve any block-table row — so TP only splits the
     head dim, and only under the 'kv' decode strategy."""
     return "model" if tp > 1 and decode_strategy(n_kv, tp) == "kv" else None
+
+
+def order_after(idx, x):
+    """`idx` (int32 indices below 2**31 - 2) itself, computed after `x`.
+
+    A scatter indexed by it waits for every read `x` depends on, so XLA
+    may update the buffer those reads came from in place. Without such a
+    data dependence XLA copies the whole buffer before the scatter, and an
+    optimization barrier does not order them."""
+    top = jnp.int32(2**31 - 1) - jnp.isnan(x).any().astype(jnp.int32)
+    return jnp.minimum(idx, top)
+
+
+def _at(layer, *idx):
+    """Index tuple into a cache leaf: `idx` into layer `layer` of a
+    scan-stacked leaf [n_rep, ...], or into the unstacked leaf itself when
+    `layer` is None. `layer` [n_rep] (`jnp.arange(n_rep)`) indexes every
+    layer at once, as a leading index dim: a slice there would make XLA
+    transpose the whole leaf around the scatter. Index arrays must stay
+    adjacent (no slice between them) so the indexed dims keep their
+    place."""
+    if layer is None:
+        return idx
+    if jnp.ndim(layer) == 1:
+        layer = jnp.reshape(layer, (-1,) + (1,) * max(map(jnp.ndim, idx)))
+    return (layer,) + idx
 
 
 # ----------------------------------------------------------------------
@@ -254,20 +285,22 @@ def dequant_pages(pages, scale, tok):
     return pages.astype(jnp.float32) * quant_effective_scale(scale, tok)
 
 
-def dequant_gather(pages, scale, tok, tables):
+def dequant_gather(pages, scale, tok, tables, *, layer=None):
     """Gather tabled blocks and dequantize → linear [B, nb·bs, K, h] f32
     (the quant twin of the `k_pages[tables]` gathers below)."""
     B, nb = tables.shape
     K, bs, h = pages.shape[-3:]
-    g = dequant_pages(pages[tables], scale[tables], tok[tables])
+    ix = _at(layer, tables)
+    g = dequant_pages(pages[ix], scale[ix], tok[ix])
     return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, K, h)
 
 
-def seal_blocks(pages, scale, tok, blocks, do_seal, *, stacked=False):
+def seal_blocks(pages, scale, tok, blocks, do_seal, *, layer=None):
     """Seal freshly-filled arena blocks: re-quantize each block's stored
     per-token payload with per-block, per-channel scales and zero its
     per-token row. pages int8 [n_rep?, N, K, bs, h]; scale [n_rep?, N, K, h];
-    tok [n_rep?, N, K, bs]; blocks [M] physical ids; do_seal [M] bool.
+    tok [n_rep?, N, K, bs] (stacked leaves take `layer`, see `_at`);
+    blocks [M] physical ids; do_seal [M] bool.
 
     Non-sealing rows are redirected to the null block 0 and write back its
     gathered content unchanged (whole-block scatters must keep real-block
@@ -278,7 +311,7 @@ def seal_blocks(pages, scale, tok, blocks, do_seal, *, stacked=False):
     blocks = jnp.asarray(blocks, jnp.int32)
     do_seal = jnp.asarray(do_seal, bool) & (blocks != 0)
     tgt = jnp.where(do_seal, blocks, 0)
-    ix = (slice(None), tgt) if stacked else tgt
+    ix = _at(layer, tgt)
     praw = pages[ix]                               # [R?, M, K, bs, h] int8
     ts = tok[ix]                                   # [R?, M, K, bs]
     deq = praw.astype(jnp.float32) * ts[..., None]
@@ -286,15 +319,14 @@ def seal_blocks(pages, scale, tok, blocks, do_seal, *, stacked=False):
     safe = jnp.where(sc > 0, sc, 1.0)
     q2 = jnp.clip(jnp.round(deq / safe[..., None, :]), -127, 127) \
         .astype(jnp.int8)
-    lead = (1,) if stacked else ()
-    m5 = do_seal.reshape(lead + (-1, 1, 1, 1))
-    m4 = do_seal.reshape(lead + (-1, 1, 1))
+    m5 = do_seal.reshape(-1, 1, 1, 1)
+    m4 = do_seal.reshape(-1, 1, 1)
     return (pages.at[ix].set(jnp.where(m5, q2, praw)),
             scale.at[ix].set(jnp.where(m4, sc, scale[ix])),
             tok.at[ix].set(jnp.where(m4, 0.0, ts)))
 
 
-def quant_paged_cache_write(entry, k_new, v_new, blk, off):
+def quant_paged_cache_write(entry, k_new, v_new, blk, off, *, layer=None):
     """Decode append into an int8 arena entry (the quant twin of
     `paged_cache_write` + the scale-plane maintenance it implies).
 
@@ -311,21 +343,24 @@ def quant_paged_cache_write(entry, k_new, v_new, blk, off):
     K = entry["k"].shape[-3]
     kq, kts = quant_tokens(k_new)
     vq, vts = quant_tokens(v_new)
-    ub = jnp.where(off == 0, blk, 0)
+    ub = _at(layer, jnp.where(off == 0, blk, 0))
     ksc = entry["kscale"].at[ub].set(0.0)
     vsc = entry["vscale"].at[ub].set(0.0)
-    kp, vp = paged_cache_write(entry["k"], entry["v"], kq, vq, blk, off)
+    kp, vp = paged_cache_write(entry["k"], entry["v"], kq, vq, blk, off,
+                               layer=layer)
     ki = jnp.arange(K)[None, :]
-    ktk = entry["ktok"].at[blk[:, None], ki, off[:, None]].set(kts)
-    vtk = entry["vtok"].at[blk[:, None], ki, off[:, None]].set(vts)
+    ix = _at(layer, blk[:, None], ki, off[:, None])
+    ktk = entry["ktok"].at[ix].set(kts)
+    vtk = entry["vtok"].at[ix].set(vts)
     do_seal = off == bs - 1
-    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, blk, do_seal)
-    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, blk, do_seal)
+    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, blk, do_seal, layer=layer)
+    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, blk, do_seal, layer=layer)
     return {"k": kp, "v": vp, "kscale": ksc, "vscale": vsc,
             "ktok": ktk, "vtok": vtk}
 
 
-def quant_paged_prefill_write(entry, k_new, v_new, tables, off, chunk_len):
+def quant_paged_prefill_write(entry, k_new, v_new, tables, off, chunk_len,
+                              *, layer=None):
     """Chunk scatter into an int8 arena entry (quant twin of
     `paged_prefill_write`): per-token quantize the chunk [1, S, K, h],
     unseal blocks the chunk opens (first token at in-block offset 0), land
@@ -340,43 +375,45 @@ def quant_paged_prefill_write(entry, k_new, v_new, tables, off, chunk_len):
     offi = pos % bs
     kq, kts = quant_tokens(k_new[0])               # [S, K, h], [S, K]
     vq, vts = quant_tokens(v_new[0])
-    ub = jnp.where(valid & (offi == 0), blk, 0)
+    ub = _at(layer, jnp.where(valid & (offi == 0), blk, 0))
     ksc = entry["kscale"].at[ub].set(0.0)
     vsc = entry["vscale"].at[ub].set(0.0)
-    ki = jnp.arange(K)[None, :]
-    kp = entry["k"].at[blk[:, None], ki, offi[:, None]].set(kq)
-    vp = entry["v"].at[blk[:, None], ki, offi[:, None]].set(vq)
-    ktk = entry["ktok"].at[blk[:, None], ki, offi[:, None]].set(kts)
-    vtk = entry["vtok"].at[blk[:, None], ki, offi[:, None]].set(vts)
+    ix = _at(layer, blk[:, None], jnp.arange(K)[None, :], offi[:, None])
+    kp = entry["k"].at[ix].set(kq)
+    vp = entry["v"].at[ix].set(vq)
+    ktk = entry["ktok"].at[ix].set(kts)
+    vtk = entry["vtok"].at[ix].set(vts)
     do_seal = valid & (offi == bs - 1)
-    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, blk, do_seal)
-    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, blk, do_seal)
+    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, blk, do_seal, layer=layer)
+    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, blk, do_seal, layer=layer)
     return {"k": kp, "v": vp, "kscale": ksc, "vscale": vsc,
             "ktok": ktk, "vtok": vtk}
 
 
-def quant_paged_cache_write_tokens(entry, k_new, v_new, blk, off):
+def quant_paged_cache_write_tokens(entry, k_new, v_new, blk, off, *,
+                                   layer=None):
     """Per-sequence token-WINDOW scatter into an int8 arena entry (quant
     twin of `paged_cache_write_tokens` — the speculative-verify commit).
     blk/off [B, S]; rejected/idle rows arrive already redirected to the
     null block, so rollback stays the absence of a write; unseal/seal
     follow the same offset-0 / offset-(bs-1) rules as the append path."""
-    B, S, K, h = k_new.shape
+    K = k_new.shape[-2]
     bs = entry["k"].shape[-2]
-    kq, kts = quant_tokens(k_new)                  # [B, S, K, h], [B, S, K]
+    kq, kts = quant_tokens(k_new)                  # [.., B, S, K, h], [.., K]
     vq, vts = quant_tokens(v_new)
-    ub = jnp.where(off == 0, blk, 0).reshape(-1)
+    ub = _at(layer, jnp.where(off == 0, blk, 0).reshape(-1))
     ksc = entry["kscale"].at[ub].set(0.0)
     vsc = entry["vscale"].at[ub].set(0.0)
-    ki = jnp.arange(K)[None, None, :]
-    kp = entry["k"].at[blk[:, :, None], ki, off[:, :, None]].set(kq)
-    vp = entry["v"].at[blk[:, :, None], ki, off[:, :, None]].set(vq)
-    ktk = entry["ktok"].at[blk[:, :, None], ki, off[:, :, None]].set(kts)
-    vtk = entry["vtok"].at[blk[:, :, None], ki, off[:, :, None]].set(vts)
+    ix = _at(layer, blk[:, :, None], jnp.arange(K)[None, None, :],
+             off[:, :, None])
+    kp = entry["k"].at[ix].set(kq)
+    vp = entry["v"].at[ix].set(vq)
+    ktk = entry["ktok"].at[ix].set(kts)
+    vtk = entry["vtok"].at[ix].set(vts)
     flat_b = blk.reshape(-1)
     do_seal = (off == bs - 1).reshape(-1)
-    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, flat_b, do_seal)
-    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, flat_b, do_seal)
+    kp, ksc, ktk = seal_blocks(kp, ksc, ktk, flat_b, do_seal, layer=layer)
+    vp, vsc, vtk = seal_blocks(vp, vsc, vtk, flat_b, do_seal, layer=layer)
     return {"k": kp, "v": vp, "kscale": ksc, "vscale": vsc,
             "ktok": ktk, "vtok": vtk}
 
@@ -384,7 +421,7 @@ def quant_paged_cache_write_tokens(entry, k_new, v_new, blk, off):
 # ----------------------------------------------------------------------
 def paged_decode_attention(q, k_pages, v_pages, tables, lens, *,
                            k_scale=None, k_tok=None, v_scale=None,
-                           v_tok=None):
+                           v_tok=None, layer=None):
     """Single-token attention over physically paged KV (pure-jnp path).
 
     q [B, H, h]; arenas [N, K, bs, h] (kv-head-major blocks); tables [B, nb]
@@ -400,15 +437,15 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lens, *,
     """
     B = q.shape[0]
     nb = tables.shape[1]
-    bs, h = k_pages.shape[2], k_pages.shape[3]
-    K = k_pages.shape[1]
+    K, bs, h = k_pages.shape[-3:]
     if k_scale is not None:
-        k_lin = dequant_gather(k_pages, k_scale, k_tok, tables)
-        v_lin = dequant_gather(v_pages, v_scale, v_tok, tables)
+        k_lin = dequant_gather(k_pages, k_scale, k_tok, tables, layer=layer)
+        v_lin = dequant_gather(v_pages, v_scale, v_tok, tables, layer=layer)
     else:
-        k_lin = k_pages[tables].transpose(0, 1, 3, 2, 4) \
+        ix = _at(layer, tables)
+        k_lin = k_pages[ix].transpose(0, 1, 3, 2, 4) \
             .reshape(B, nb * bs, K, h)
-        v_lin = v_pages[tables].transpose(0, 1, 3, 2, 4) \
+        v_lin = v_pages[ix].transpose(0, 1, 3, 2, 4) \
             .reshape(B, nb * bs, K, h)
     return decode_attention(q, k_lin, v_lin, lens)
 
@@ -416,7 +453,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lens, *,
 def paged_prefill_attention(q, k_new, v_new, k_pages, v_pages, tables, off,
                             chunk_len, *, mask_window: int = 0,
                             mask_sink: int = 0, k_scale=None, k_tok=None,
-                            v_scale=None, v_tok=None):
+                            v_scale=None, v_tok=None, layer=None):
     """Chunked-prefill attention over paged history (pure-jnp path).
 
     q [B,S,H,h] is one prompt chunk at absolute positions off + arange(S)
@@ -435,17 +472,18 @@ def paged_prefill_attention(q, k_new, v_new, k_pages, v_pages, tables, off,
     K = k_new.shape[2]
     G = H // K
     nb = tables.shape[1]
-    bs = k_pages.shape[2]
+    bs = k_pages.shape[-2]
     L = nb * bs
     f32 = jnp.float32
     off = jnp.broadcast_to(jnp.asarray(off, jnp.int32), (B,))
     cl = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (B,))
     if k_scale is not None:
-        k_hist = dequant_gather(k_pages, k_scale, k_tok, tables)
-        v_hist = dequant_gather(v_pages, v_scale, v_tok, tables)
+        k_hist = dequant_gather(k_pages, k_scale, k_tok, tables, layer=layer)
+        v_hist = dequant_gather(v_pages, v_scale, v_tok, tables, layer=layer)
     else:
-        k_hist = k_pages[tables].transpose(0, 1, 3, 2, 4).reshape(B, L, K, h)
-        v_hist = v_pages[tables].transpose(0, 1, 3, 2, 4).reshape(B, L, K, h)
+        ix = _at(layer, tables)
+        k_hist = k_pages[ix].transpose(0, 1, 3, 2, 4).reshape(B, L, K, h)
+        v_hist = v_pages[ix].transpose(0, 1, 3, 2, 4).reshape(B, L, K, h)
     pos = off[:, None] + jnp.arange(S)[None]                 # [B, S] q pos
     tok = jnp.concatenate(
         [jnp.broadcast_to(jnp.arange(L)[None], (B, L)), pos], axis=1)
@@ -471,7 +509,7 @@ def paged_prefill_attention(q, k_new, v_new, k_pages, v_pages, tables, off,
 
 
 def paged_prefill_write(k_pages, v_pages, k_new, v_new, tables, off,
-                        chunk_len):
+                        chunk_len, *, layer=None):
     """Scatter one chunk's K/V [B,S,K,h] (B == 1) into arena blocks.
 
     Chunk token i lands at absolute position off + i → physical block
@@ -480,27 +518,26 @@ def paged_prefill_write(k_pages, v_pages, k_new, v_new, tables, off,
     clobbering is harmless (null contents are masked everywhere).
     """
     B, S, K, h = k_new.shape
-    bs = k_pages.shape[2]
+    bs = k_pages.shape[-2]
     nb = tables.shape[1]
     pos = jnp.asarray(off, jnp.int32) + jnp.arange(S)
     valid = jnp.arange(S) < jnp.asarray(chunk_len, jnp.int32)
     blk = jnp.where(valid, tables[0, jnp.clip(pos // bs, 0, nb - 1)], 0)
     offi = pos % bs
-    ki = jnp.arange(K)[None, :]
-    k_pages = k_pages.at[blk[:, None], ki, offi[:, None]].set(
-        k_new[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[blk[:, None], ki, offi[:, None]].set(
-        v_new[0].astype(v_pages.dtype))
+    ix = _at(layer, blk[:, None], jnp.arange(K)[None, :], offi[:, None])
+    k_pages = k_pages.at[ix].set(k_new[0].astype(k_pages.dtype))
+    v_pages = v_pages.at[ix].set(v_new[0].astype(v_pages.dtype))
     return k_pages, v_pages
 
 
 def update_block_summaries(kmin, kmax, kmean, k_pages, blocks, *,
-                           stacked=False, k_scale=None, k_tok=None):
+                           k_scale=None, k_tok=None, layer=None):
     """Recompute the per-block key summaries for the (just-written) blocks.
 
     kmin/kmax/kmean [N, K, h] float32 side arrays of a [N, K, bs, h] key
-    arena (with a leading n_rep axis on both when `stacked` — the scan-
-    stacked period arenas); blocks [M] physical block ids — duplicates are
+    arena (with a leading n_rep axis on both for the scan-stacked period
+    arenas, which take `layer`: see `_at`); blocks [M] physical block
+    ids — duplicates are
     fine (every duplicate recomputes the same value from the same updated
     arena content, so scatter order does not matter). Summaries are exact
     whole-block reductions: unwritten slots hold zeros, which only widen
@@ -515,24 +552,17 @@ def update_block_summaries(kmin, kmax, kmean, k_pages, blocks, *,
     through it so the zero-stale-summary (and zero-stale-scale) invariant
     cannot diverge between paths.
     """
-    blocks = jnp.asarray(blocks, jnp.int32)
-    if stacked:
-        k = k_pages[:, blocks].astype(jnp.float32)       # [R, M, K, bs, h]
-        if k_scale is not None:
-            k = k * quant_effective_scale(k_scale[:, blocks],
-                                          k_tok[:, blocks])
-        ix = (slice(None), blocks)
-    else:
-        k = k_pages[blocks].astype(jnp.float32)          # [M, K, bs, h]
-        if k_scale is not None:
-            k = k * quant_effective_scale(k_scale[blocks], k_tok[blocks])
-        ix = blocks
+    ix = _at(layer, jnp.asarray(blocks, jnp.int32))
+    k = k_pages[ix].astype(jnp.float32)                  # [R?, M, K, bs, h]
+    if k_scale is not None:
+        k = k * quant_effective_scale(k_scale[ix], k_tok[ix])
     return (kmin.at[ix].set(k.min(axis=-2)),
             kmax.at[ix].set(k.max(axis=-2)),
             kmean.at[ix].set(k.mean(axis=-2)))
 
 
-def block_topk_scores(q, kmin, kmax, tables, lens, *, block_size):
+def block_topk_scores(q, kmin, kmax, tables, lens, *, block_size,
+                      layer=None):
     """Quest-style upper-bound block scores (pure-jnp path).
 
     q [B, H, h]; kmin/kmax [N, K, h]; tables [B, nb] physical block ids;
@@ -544,11 +574,12 @@ def block_topk_scores(q, kmin, kmax, tables, lens, *, block_size):
     tiles — this fallback pays the full gather.
     """
     B, H, h = q.shape
-    K = kmin.shape[1]
+    K = kmin.shape[-2]
     G = H // K
     nb = tables.shape[1]
-    lo = kmin[tables].astype(jnp.float32)                # [B, nb, K, h]
-    hi = kmax[tables].astype(jnp.float32)
+    ix = _at(layer, tables)
+    lo = kmin[ix].astype(jnp.float32)                    # [B, nb, K, h]
+    hi = kmax[ix].astype(jnp.float32)
     qg = q.reshape(B, K, G, h).astype(jnp.float32)[:, None]
     ub = jnp.maximum(qg * lo[:, :, :, None, :],
                      qg * hi[:, :, :, None, :]).sum(-1)  # [B, nb, K, G]
@@ -610,7 +641,7 @@ def select_kv_blocks(scores, tables, lens, *, block_size, k_static,
 
 
 def selected_attention_mass(q, k_pages, tables, lens, selected, *,
-                            k_scale=None, k_tok=None):
+                            k_scale=None, k_tok=None, layer=None):
     """Exact attention mass the selected blocks capture, per slot.
 
     q [B, H, h]; k_pages [N, K, bs, h]; tables/selected [B, nb] over the
@@ -622,13 +653,13 @@ def selected_attention_mass(q, k_pages, tables, lens, selected, *,
     the content attention actually reads.
     """
     B, H, h = q.shape
-    K, bs = k_pages.shape[1], k_pages.shape[2]
+    K, bs = k_pages.shape[-3], k_pages.shape[-2]
     G = H // K
     nb = tables.shape[1]
     if k_scale is not None:
-        k_lin = dequant_gather(k_pages, k_scale, k_tok, tables)
+        k_lin = dequant_gather(k_pages, k_scale, k_tok, tables, layer=layer)
     else:
-        k_lin = k_pages[tables].transpose(0, 1, 3, 2, 4) \
+        k_lin = k_pages[_at(layer, tables)].transpose(0, 1, 3, 2, 4) \
             .reshape(B, nb * bs, K, h).astype(jnp.float32)
     qg = q.reshape(B, K, G, h).astype(jnp.float32)
     s = jnp.einsum("bkgh,bwkh->bkgw", qg, k_lin) * (h ** -0.5)
@@ -639,7 +670,8 @@ def selected_attention_mass(q, k_pages, tables, lens, selected, *,
     return (p * slot_sel[:, None, None, :]).sum(-1).mean(axis=(1, 2))
 
 
-def paged_cache_write(k_pages, v_pages, k_new, v_new, blk, off):
+def paged_cache_write(k_pages, v_pages, k_new, v_new, blk, off, *,
+                      layer=None):
     """Write one token's K/V per sequence into arena blocks.
 
     arenas [N, K, bs, h]; k_new/v_new [B, K, h]; blk/off [B] physical block
@@ -647,16 +679,15 @@ def paged_cache_write(k_pages, v_pages, k_new, v_new, blk, off):
     blocks (append-only block ownership); freed slots are redirected to the
     null block by the caller, where duplicate writes are harmless.
     """
-    K = k_pages.shape[1]
-    ki = jnp.arange(K)[None, :]
-    k_pages = k_pages.at[blk[:, None], ki, off[:, None]].set(
-        k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[blk[:, None], ki, off[:, None]].set(
-        v_new.astype(v_pages.dtype))
+    K = k_pages.shape[-3]
+    ix = _at(layer, blk[:, None], jnp.arange(K)[None, :], off[:, None])
+    k_pages = k_pages.at[ix].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[ix].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages
 
 
-def paged_cache_write_tokens(k_pages, v_pages, k_new, v_new, blk, off):
+def paged_cache_write_tokens(k_pages, v_pages, k_new, v_new, blk, off, *,
+                             layer=None):
     """Write a per-sequence token WINDOW into arena blocks.
 
     arenas [N, K, bs, h]; k_new/v_new [B, S, K, h] (S window rows per
@@ -667,33 +698,31 @@ def paged_cache_write_tokens(k_pages, v_pages, k_new, v_new, blk, off):
     sequences own distinct blocks and a window's rows occupy distinct
     (block, offset) slots, so scatter order is irrelevant outside null.
     """
-    K = k_pages.shape[1]
-    ki = jnp.arange(K)[None, None, :]
-    k_pages = k_pages.at[blk[:, :, None], ki, off[:, :, None]].set(
-        k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[blk[:, :, None], ki, off[:, :, None]].set(
-        v_new.astype(v_pages.dtype))
+    K = k_pages.shape[-3]
+    ix = _at(layer, blk[:, :, None], jnp.arange(K)[None, None, :],
+             off[:, :, None])
+    k_pages = k_pages.at[ix].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[ix].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages
 
 
 def paged_cache_write_tokens_masked(k_pages, v_pages, k_new, v_new, blk, off,
-                                    write):
+                                    write, *, layer=None):
     """`paged_cache_write_tokens` for arenas WITHOUT a null block (the ring
     arenas): rows with write[b,s] False write back the slot's CURRENT
     content (gather-then-where, the `prefill_resume_attention` idiom), so a
     rejected draft row is a bit-exact no-op on its target slot. Callers must
     keep each sequence's masked-in rows on distinct (blk, off) slots."""
-    K = k_pages.shape[1]
-    ki = jnp.arange(K)[None, None, :]
-    bi = blk[:, :, None]
-    oi = off[:, :, None]
-    cur_k = k_pages[bi, ki, oi]                          # [B, S, K, h]
-    cur_v = v_pages[bi, ki, oi]
+    K = k_pages.shape[-3]
+    ix = _at(layer, blk[:, :, None], jnp.arange(K)[None, None, :],
+             off[:, :, None])
+    cur_k = k_pages[ix]                                  # [.., B, S, K, h]
+    cur_v = v_pages[ix]
     wm = write[:, :, None, None]
     k_wr = jnp.where(wm, k_new.astype(k_pages.dtype), cur_k)
     v_wr = jnp.where(wm, v_new.astype(v_pages.dtype), cur_v)
-    k_pages = k_pages.at[bi, ki, oi].set(k_wr)
-    v_pages = v_pages.at[bi, ki, oi].set(v_wr)
+    k_pages = k_pages.at[ix].set(k_wr)
+    v_pages = v_pages.at[ix].set(v_wr)
     return k_pages, v_pages
 
 
@@ -703,7 +732,8 @@ def ring_slot(t, sink: int, recent: int):
     return jnp.where(t < W, t, sink + (t - sink) % recent)
 
 
-def cache_write(k_cache, v_cache, k_new, v_new, t, *, sink: int = 0, recent: int = 0):
+def cache_write(k_cache, v_cache, k_new, v_new, t, *, sink: int = 0,
+                recent: int = 0, layer=None):
     """Write one token's K/V at position t (scalar, or [B] per-request).
     Full cache when sink==recent==0 (slot=t), else sink+recent ring layout."""
     t = jnp.asarray(t)
@@ -712,12 +742,21 @@ def cache_write(k_cache, v_cache, k_new, v_new, t, *, sink: int = 0, recent: int
     else:
         idx = t
     if t.ndim:                        # per-request write positions
-        b = jnp.arange(k_cache.shape[0])
-        k_cache = k_cache.at[b, idx].set(k_new)
-        v_cache = v_cache.at[b, idx].set(v_new)
+        ix = _at(layer, jnp.arange(k_new.shape[0]), idx)
+        k_cache = k_cache.at[ix].set(k_new)
+        v_cache = v_cache.at[ix].set(v_new)
         return k_cache, v_cache
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new[:, None], idx, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new[:, None], idx, axis=1)
+    if layer is None:
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k_new[:, None], idx, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v_new[:, None], idx, axis=1)
+        return k_cache, v_cache
+    start = (layer, 0, idx, 0, 0)
+    k_cache = jax.lax.dynamic_update_slice(k_cache, k_new[None, :, None],
+                                           start)
+    v_cache = jax.lax.dynamic_update_slice(v_cache, v_new[None, :, None],
+                                           start)
     return k_cache, v_cache
 
 
@@ -807,7 +846,7 @@ def spec_verify_ring_attention(q, k_new, v_new, k_cache, v_cache, positions,
 def prefill_resume_attention(q, k_new, v_new, k_cache, v_cache, positions, *,
                              chunk_len, sink: int, recent: int,
                              mask_window: int = 0, mask_sink: int = 0,
-                             attend_limit: int = 0):
+                             attend_limit: int = 0, layer=None):
     """Exact continuation-prefill attention for one chunk.
 
     q [B,S,H,h], k_new/v_new [B,S,K,h] at absolute `positions` [S]
@@ -829,10 +868,12 @@ def prefill_resume_attention(q, k_new, v_new, k_cache, v_cache, positions, *,
     B, S, H, h = q.shape
     K = k_new.shape[2]
     G = H // K
-    k_att, v_att = k_cache, v_cache
+    Wc = k_cache.shape[-3]
+    lim = Wc
     if attend_limit and not (sink or recent):
-        lim = min(attend_limit, k_cache.shape[1])
-        k_att, v_att = k_cache[:, :lim], v_cache[:, :lim]
+        lim = min(attend_limit, Wc)
+    att = _at(layer, slice(None), slice(0, lim))
+    k_att, v_att = k_cache[att], v_cache[att]
     W = k_att.shape[1]
     scale = h ** -0.5
     f32 = jnp.float32
@@ -863,15 +904,19 @@ def prefill_resume_attention(q, k_new, v_new, k_cache, v_cache, positions, *,
     out = jnp.einsum("bskgw,bwkh->bskgh", p_att, v_all)
     out = out.reshape(B, S, H, h).astype(q.dtype)
 
-    slots = ring_slot(pos, sink, recent) if (sink or recent) else pos
-    safe = jnp.clip(slots, 0, k_cache.shape[1] - 1)
+    # the chunk lands after the reads above
+    slots = order_after(ring_slot(pos, sink, recent) if (sink or recent)
+                        else pos, out)
+    safe = jnp.clip(slots, 0, Wc - 1)
+    bi = jnp.arange(B)[:, None]
     vq = valid_q[None, :, None, None]
     k_wr = jnp.where(vq, k_new.astype(k_cache.dtype),
-                     jnp.take(k_cache, safe, axis=1))
+                     k_cache[_at(layer, bi, safe[None, :])])
     v_wr = jnp.where(vq, v_new.astype(v_cache.dtype),
-                     jnp.take(v_cache, safe, axis=1))
-    k_cache = k_cache.at[:, slots].set(k_wr, mode="drop")
-    v_cache = v_cache.at[:, slots].set(v_wr, mode="drop")
+                     v_cache[_at(layer, bi, safe[None, :])])
+    ix = _at(layer, bi, slots[None, :])
+    k_cache = k_cache.at[ix].set(k_wr, mode="drop")
+    v_cache = v_cache.at[ix].set(v_wr, mode="drop")
     return out, k_cache, v_cache
 
 

@@ -262,9 +262,8 @@ class LM:
             token_mask=tm, block_tables=block_tables)
         return self._logits(params, x), staged, aux
 
-    def verify_commit(self, cache, staged, positions, n_write, block_tables):
+    def verify_commit(self, staged, positions, n_write, block_tables):
         """Land the accepted prefix of a `verify` window — n_write [B] rows
-        per slot — in the paged caches; see stack_verify_commit."""
+        per slot — in the paged caches it read; see stack_verify_commit."""
         return stack_mod.stack_verify_commit(
-            self.cfg, self.plan, cache, staged, positions, n_write,
-            block_tables)
+            self.cfg, self.plan, staged, positions, n_write, block_tables)

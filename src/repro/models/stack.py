@@ -472,15 +472,24 @@ def regroup_params(params: dict, plan_from: StackPlan, plan_to: StackPlan) -> di
 
 # ======================================================================
 # Layer application
+def _layer_leaves(leaves: dict, layer) -> dict:
+    """One layer's slice of each leaf of a flat cache dict (the leaves
+    themselves when unstacked) — for the Pallas kernels, which take
+    unstacked arenas, and for dense reads of a whole layer."""
+    return leaves if layer is None else {n: a[layer] for n, a in leaves.items()}
+
+
 def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpec,
                   mode: str, positions, cache, max_len: int, batch_part,
                   true_len=None, attend_limit: int = 0, block_tables=None,
-                  token_mask=None):
+                  token_mask=None, layer=None):
     """→ (x, new_cache | None, sparsity_aux | None). sparsity_aux is a [4]
     f32 vector [blocks_scored, blocks_attended, mass_sum, mass_n] emitted
     by paged-decode full-attention layers when online top-k selection is
     configured (cfg.omniattn.topk_*), weighted by `token_mask` (live
-    slots)."""
+    slots). With `layer=i` the cache leaves are scan-stacked [n_rep, ...]
+    and this is layer i of them: reads gather and writes scatter that
+    layer's rows in place, and new_cache is the whole stacked entry."""
     B = x.shape[0]
     H, K, h = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cd = jnp.dtype(cfg.compute_dtype)
@@ -523,20 +532,23 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
             if quant else {}
         if use_pallas:
             from repro.kernels import ops as kops
+            lc = _layer_leaves({"k": cache["k"], "v": cache["v"]}, layer)
             out = kops.attention_paged_prefill_op(
-                q, k, v, cache["k"], cache["v"], block_tables, pos0, cl,
-                **qkw)
+                q, k, v, lc["k"], lc["v"], block_tables, pos0, cl,
+                **_layer_leaves(qkw, layer))
         else:
             out = attn_mod.paged_prefill_attention(
                 q, k, v, cache["k"], cache["v"], block_tables, pos0, cl,
-                **qkw)
+                layer=layer, **qkw)
+        # the chunk lands after the history reads above
+        wtbl = attn_mod.order_after(block_tables, out)
         if quant:
             new_cache = attn_mod.quant_paged_prefill_write(
-                cache, k, v, block_tables, pos0, cl)
+                cache, k, v, wtbl, pos0, cl, layer=layer)
         else:
             kc, vc = attn_mod.paged_prefill_write(cache["k"], cache["v"],
-                                                  k, v, block_tables, pos0,
-                                                  cl)
+                                                  k, v, wtbl, pos0, cl,
+                                                  layer=layer)
             new_cache = {"k": kc, "v": vc}
         y = out.reshape(B, S, H * h)
         if "kmin" in cache:
@@ -554,7 +566,7 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
                 cache["kmin"], cache["kmax"], cache["kmean"],
                 new_cache["k"], wblk,
                 k_scale=new_cache.get("kscale"),
-                k_tok=new_cache.get("ktok"))
+                k_tok=new_cache.get("ktok"), layer=layer)
             new_cache.update(kmin=kmn, kmax=kmx, kmean=kme)
     elif mode == "prefill" and cache is not None:
         # continuation chunk (chunked prefill / radix prefix-KV resume):
@@ -570,7 +582,7 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
             chunk_len=(S if true_len is None else true_len),
             sink=sink, recent=recent,
             mask_window=mask_window, mask_sink=mask_sink,
-            attend_limit=attend_limit)
+            attend_limit=attend_limit, layer=layer)
         y = out.reshape(B, S, H * h)
         new_cache = {"k": kc, "v": vc}
     elif mode == "decode" and block_tables is not None:
@@ -580,7 +592,7 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
         pos = jnp.asarray(positions)
         t = pos[:, 0] if pos.ndim == 2 else (pos[0] if pos.ndim == 1 else pos)
         t = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
-        bs = cache["k"].shape[2]
+        bs = cache["k"].shape[-2]
         bidx = jnp.arange(B, dtype=jnp.int32)
         if sink or recent:
             W = sink + recent
@@ -605,11 +617,12 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
             # QuantPlane append: per-token int8 quantize + scale-plane
             # maintenance (unseal-on-open / seal-on-full) in one helper
             new_cache = attn_mod.quant_paged_cache_write(
-                cache, k[:, 0], v[:, 0], blk, off)
+                cache, k[:, 0], v[:, 0], blk, off, layer=layer)
             kc, vc = new_cache["k"], new_cache["v"]
         else:
             kc, vc = attn_mod.paged_cache_write(cache["k"], cache["v"],
-                                                k[:, 0], v[:, 0], blk, off)
+                                                k[:, 0], v[:, 0], blk, off,
+                                                layer=layer)
             new_cache = {"k": kc, "v": vc}
         qkw = dict(k_scale=new_cache["kscale"], k_tok=new_cache["ktok"],
                    v_scale=new_cache["vscale"], v_tok=new_cache["vtok"]) \
@@ -621,7 +634,7 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
             kmn, kmx, kme = attn_mod.update_block_summaries(
                 cache["kmin"], cache["kmax"], cache["kmean"], kc, blk,
                 k_scale=new_cache.get("kscale"),
-                k_tok=new_cache.get("ktok"))
+                k_tok=new_cache.get("ktok"), layer=layer)
             new_cache.update(kmin=kmn, kmax=kmx, kmean=kme)
             oa = cfg.omniattn
             k_static = topk_block_budget(oa, tbl.shape[1])
@@ -636,11 +649,14 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
                     # outside the budget are never gathered downstream
                     if use_pallas:
                         from repro.kernels import ops as kops
+                        sm = _layer_leaves({"lo": kmn, "hi": kmx}, layer)
                         scores = kops.block_topk_scores_op(
-                            q[:, 0], kmn, kmx, tbl, lens, block_size=bs)
+                            q[:, 0], sm["lo"], sm["hi"], tbl, lens,
+                            block_size=bs)
                     else:
                         scores = attn_mod.block_topk_scores(
-                            q[:, 0], kmn, kmx, tbl, lens, block_size=bs)
+                            q[:, 0], kmn, kmx, tbl, lens, block_size=bs,
+                            layer=layer)
                     tbl_s, lens_s, m, selected = attn_mod.select_kv_blocks(
                         scores, tbl, lens, block_size=bs, k_static=k_static,
                         frac=0.0 if oa.topk_blocks > 0 else oa.topk_frac,
@@ -650,7 +666,7 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
                         mass = attn_mod.selected_attention_mass(
                             q[:, 0], kc, tbl, lens, selected,
                             k_scale=new_cache.get("kscale"),
-                            k_tok=new_cache.get("ktok"))
+                            k_tok=new_cache.get("ktok"), layer=layer)
                         mass_sum, mass_n = (act * mass).sum(), act.sum()
                     else:
                         mass_sum = mass_n = jnp.float32(0)
@@ -664,11 +680,13 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
                     sp_aux = jnp.stack([scored, scored, mn, mn])
         if use_pallas:
             from repro.kernels import ops as kops
-            out = kops.attention_paged_decode_op(q[:, 0], kc, vc, tbl, lens,
-                                                 **qkw)
+            lc = _layer_leaves({"k": kc, "v": vc}, layer)
+            out = kops.attention_paged_decode_op(
+                q[:, 0], lc["k"], lc["v"], tbl, lens,
+                **_layer_leaves(qkw, layer))
         else:
             out = attn_mod.paged_decode_attention(q[:, 0], kc, vc, tbl, lens,
-                                                  **qkw)
+                                                  layer=layer, **qkw)
         y = out.reshape(B, 1, H * h)
     elif mode == "verify":
         # speculative verify: READ-ONLY attention of each slot's draft
@@ -681,12 +699,13 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
         if sink or recent:
             # gather the slot's frozen ring blocks into a dense [B, W]
             # view (slot b statically owns blocks [b·bpw, (b+1)·bpw))
-            bs_a = cache["k"].shape[2]
+            lc = _layer_leaves({"k": cache["k"], "v": cache["v"]}, layer)
+            bs_a = lc["k"].shape[2]
             bpw = ring_block_count(sink, recent, bs_a)
             W = sink + recent
-            kr = jnp.moveaxis(cache["k"].reshape(B, bpw, K, bs_a, h), 2, 3) \
+            kr = jnp.moveaxis(lc["k"].reshape(B, bpw, K, bs_a, h), 2, 3) \
                 .reshape(B, bpw * bs_a, K, h)[:, :W]
-            vr = jnp.moveaxis(cache["v"].reshape(B, bpw, K, bs_a, h), 2, 3) \
+            vr = jnp.moveaxis(lc["v"].reshape(B, bpw, K, bs_a, h), 2, 3) \
                 .reshape(B, bpw * bs_a, K, h)[:, :W]
             out = attn_mod.spec_verify_ring_attention(
                 q, k, v, kr, vr, pos2, sink=sink, recent=recent)
@@ -697,27 +716,32 @@ def attn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpe
                 if "kscale" in cache else {}
             if use_pallas:
                 from repro.kernels import ops as kops
-                out = kops.spec_verify_op(q, k, v, cache["k"], cache["v"],
+                lc = _layer_leaves({"k": cache["k"], "v": cache["v"]}, layer)
+                out = kops.spec_verify_op(q, k, v, lc["k"], lc["v"],
                                           block_tables, t,
-                                          jnp.full_like(t, S), **qkw)
+                                          jnp.full_like(t, S),
+                                          **_layer_leaves(qkw, layer))
             else:
                 out = attn_mod.paged_prefill_attention(
                     q, k, v, cache["k"], cache["v"], block_tables, t, S,
-                    **qkw)
+                    layer=layer, **qkw)
         y = out.reshape(B, S, H * h)
         new_cache = {"k": k, "v": v}
     elif mode == "decode":
         pos = jnp.asarray(positions)
         t = pos[:, 0] if pos.ndim == 2 else (pos[0] if pos.ndim == 1 else pos)
         kc, vc = attn_mod.cache_write(cache["k"], cache["v"], k[:, 0], v[:, 0], t,
-                                      sink=sink, recent=recent)
+                                      sink=sink, recent=recent, layer=layer)
+        # dense attention reads the whole per-slot window of this layer
+        lc = _layer_leaves({"k": kc, "v": vc}, layer)
         if use_pallas:
             from repro.kernels import ops as kops
-            out = kops.attention_decode_op(q[:, 0], kc, vc, t + 1)
+            out = kops.attention_decode_op(q[:, 0], lc["k"], lc["v"], t + 1)
         else:
             strat = attn_mod.decode_strategy(K, mesh.tp)
-            out = attn_mod.decode_attention(q[:, 0], kc, vc, t + 1, mesh=mesh,
-                                            strategy=strat, batch_part=batch_part)
+            out = attn_mod.decode_attention(q[:, 0], lc["k"], lc["v"], t + 1,
+                                            mesh=mesh, strategy=strat,
+                                            batch_part=batch_part)
         y = out.reshape(B, 1, H * h)
         new_cache = {"k": kc, "v": vc}
     elif use_pallas:
@@ -868,7 +892,9 @@ def ffn_sublayer(cfg: ModelConfig, mesh: MeshCtx, p: dict, x, *, spec: LayerSpec
 
 def apply_layer(cfg, mesh, spec: LayerSpec, p: dict, x, *, mode, positions,
                 cache, max_len, batch_part, true_len=None, attend_limit=0,
-                token_mask=None, block_tables=None):
+                token_mask=None, block_tables=None, layer=None):
+    """One layer. `layer=i`: the cache leaves are scan-stacked and this is
+    layer i of them; the returned cache is then the updated stacked entry."""
     sp = None
     if spec.kind == "attn":
         x, nc, sp = attn_sublayer(cfg, mesh, p, x, spec=spec, mode=mode,
@@ -877,10 +903,15 @@ def apply_layer(cfg, mesh, spec: LayerSpec, p: dict, x, *, mode, positions,
                                   true_len=true_len,
                                   attend_limit=attend_limit,
                                   block_tables=block_tables,
-                                  token_mask=token_mask)
+                                  token_mask=token_mask, layer=layer)
     else:
-        x, nc = mamba_sublayer(cfg, mesh, p, x, mode=mode, cache=cache,
+        # the recurrent state is rewritten whole every call
+        x, nc = mamba_sublayer(cfg, mesh, p, x, mode=mode,
+                               cache=(None if cache is None else
+                                      _layer_leaves(cache, layer)),
                                batch_part=batch_part, true_len=true_len)
+        if layer is not None and nc is not None:
+            nc = {n: cache[n].at[layer].set(a) for n, a in nc.items()}
     x, counts = ffn_sublayer(cfg, mesh, p, x, spec=spec, batch_part=batch_part,
                              token_mask=token_mask)
     x = mesh.constrain(x, P(batch_part, None, None))
@@ -905,31 +936,40 @@ def stack_apply(cfg: ModelConfig, mesh: MeshCtx, plan: StackPlan, params: dict,
             p["_tables"] = tables
         return p
 
+    # An input cache rides the scan's carry, whole and stacked, and each
+    # layer reads and writes only its own rows of it by the scan counter:
+    # as xs/ys every layer would slice its whole cache out and write it
+    # back into a fresh stacked buffer. Caches built here (prefill with no
+    # input cache) and verify's staged window K/V come out as ys.
     has_cache = caches is not None
-    period_caches = caches["period"] if has_cache else tuple(None for _ in plan.period)
+    writes = has_cache and mode != "verify"
+    period_caches = caches["period"] if has_cache \
+        else tuple(None for _ in plan.period)
 
     def body(carry, xs):
-        h = carry
-        p_slices = xs[0]
-        c_slices = xs[1] if has_cache else tuple(None for _ in plan.period)
-        new_cs, counts, sps = [], [], []
+        h, cs = carry
+        p_slices, li = xs
+        cs, new_cs, counts, sps = list(cs), [], [], []
         for i, spec in enumerate(plan.period):
             h, nc, cnt, sp = apply_layer(cfg, mesh, spec,
                                          with_tables(p_slices[i]), h,
                                          mode=mode, positions=positions,
-                                         cache=c_slices[i], max_len=max_len,
+                                         cache=cs[i], max_len=max_len,
                                          batch_part=batch_part,
                                          true_len=true_len,
                                          attend_limit=attend_limit,
                                          token_mask=token_mask,
-                                         block_tables=block_tables)
-            if nc is not None:
+                                         block_tables=block_tables,
+                                         layer=li if has_cache else None)
+            if writes:
+                cs[i] = nc
+            elif nc is not None:
                 new_cs.append(nc)
             if cnt is not None:
                 counts.append(cnt)
             if sp is not None:
                 sps.append(sp)
-        return h, (tuple(new_cs), tuple(counts), tuple(sps))
+        return (h, tuple(cs)), (tuple(new_cs), tuple(counts), tuple(sps))
 
     if cfg.remat and mode == "train":
         policy = (jax.checkpoint_policies.dots_saveable
@@ -937,11 +977,14 @@ def stack_apply(cfg: ModelConfig, mesh: MeshCtx, plan: StackPlan, params: dict,
                   else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy)
 
-    xs = (params["period"], period_caches) if has_cache else (params["period"],)
     if plan.n_rep > 0 and plan.period:
-        x, (new_period_caches, period_counts, period_sparsity) = \
-            jax.lax.scan(body, x, xs)
+        (x, carried), (new_period_caches, period_counts, period_sparsity) = \
+            jax.lax.scan(body, (x, period_caches),
+                         (params["period"], jnp.arange(plan.n_rep)))
+        if writes:
+            new_period_caches = carried
     else:
+        carried = period_caches
         new_period_caches, period_counts, period_sparsity = (), (), ()
 
     new_rem_caches, rem_counts, rem_sparsity = [], [], []
@@ -972,8 +1015,11 @@ def stack_apply(cfg: ModelConfig, mesh: MeshCtx, plan: StackPlan, params: dict,
         # period entries arrive scan-stacked [n_rep, B, S, K, h]. The caller
         # decides acceptance, then lands only the accepted prefix via
         # stack_verify_commit; until then the real caches are untouched.
+        # "cache" is the input cache as the scan carried it, unchanged: the
+        # commit writes into those buffers, so the input needs no copy.
         new_caches = {"period": new_period_caches,
-                      "rem": tuple(new_rem_caches)}
+                      "rem": tuple(new_rem_caches),
+                      "cache": dict(caches, period=carried)}
     aux = {"period_counts": period_counts, "rem_counts": tuple(rem_counts),
            # per-layer online-sparsity vectors [blocks_scored,
            # blocks_attended, mass_sum, mass_n] — period entries arrive
@@ -984,13 +1030,13 @@ def stack_apply(cfg: ModelConfig, mesh: MeshCtx, plan: StackPlan, params: dict,
 
 
 # ======================================================================
-def stack_verify_commit(cfg: ModelConfig, plan: StackPlan, caches, staged,
+def stack_verify_commit(cfg: ModelConfig, plan: StackPlan, staged,
                         positions, n_write, block_tables):
     """Land a speculative verify window's ACCEPTED prefix in the paged caches.
 
-    caches: the paged cache pytree the verify forward read (untouched by
-    it); staged: stack_apply(mode="verify")'s second return — each
-    attention layer's rope'd window K/V; positions [B] window start (the
+    staged: stack_apply(mode="verify")'s second return — each attention
+    layer's rope'd window K/V, and under "cache" the paged cache pytree
+    the verify forward read (untouched by it); positions [B] window start (the
     pre-verify slot cursor); n_write [B] rows to land per slot — the
     CONSUMED input tokens (current token + accepted drafts; 0 for idle
     slots); block_tables [B, nb].
@@ -1007,6 +1053,7 @@ def stack_verify_commit(cfg: ModelConfig, plan: StackPlan, caches, staged,
     caps the draft window). Returns the updated cache pytree; "pos"
     advances to the furthest committed cursor like a decode step's cache.
     """
+    caches = staged["cache"]
     positions = jnp.asarray(positions, jnp.int32)
     n_write = jnp.asarray(n_write, jnp.int32)
     B = positions.shape[0]
@@ -1016,7 +1063,7 @@ def stack_verify_commit(cfg: ModelConfig, plan: StackPlan, caches, staged,
     valid = jnp.arange(S, dtype=jnp.int32)[None] < n_write[:, None]
     bidx = jnp.arange(B, dtype=jnp.int32)
 
-    def commit_full(entry, stg):
+    def commit_full(entry, stg, layer):
         bs = entry["k"].shape[-2]
         nb = block_tables.shape[1]
         blk = jnp.where(valid & (pos2 < nb * bs),
@@ -1029,36 +1076,38 @@ def stack_verify_commit(cfg: ModelConfig, plan: StackPlan, caches, staged,
             # stays "the write never happened" for payload AND scale plane
             out = dict(entry)
             out.update(attn_mod.quant_paged_cache_write_tokens(
-                entry, stg["k"], stg["v"], blk, off))
+                entry, stg["k"], stg["v"], blk, off, layer=layer))
         else:
             kc, vc = attn_mod.paged_cache_write_tokens(
-                entry["k"], entry["v"], stg["k"], stg["v"], blk, off)
+                entry["k"], entry["v"], stg["k"], stg["v"], blk, off,
+                layer=layer)
             out = dict(entry, k=kc, v=vc)
         if "kmin" in entry:
             kmn, kmx, kme = attn_mod.update_block_summaries(
                 entry["kmin"], entry["kmax"], entry["kmean"], out["k"],
                 blk.reshape(-1), k_scale=out.get("kscale"),
-                k_tok=out.get("ktok"))
+                k_tok=out.get("ktok"), layer=layer)
             out.update(kmin=kmn, kmax=kmx, kmean=kme)
         return out
 
-    def commit_ring(entry, stg, sink, recent):
+    def commit_ring(entry, stg, layer, sink, recent):
         bs = entry["k"].shape[-2]
         bpw = ring_block_count(sink, recent, bs)
         slot = attn_mod.ring_slot(pos2, sink, recent)
         blk = bidx[:, None] * bpw + slot // bs
         off = slot % bs
         kc, vc = attn_mod.paged_cache_write_tokens_masked(
-            entry["k"], entry["v"], stg["k"], stg["v"], blk, off, valid)
+            entry["k"], entry["v"], stg["k"], stg["v"], blk, off, valid,
+            layer=layer)
         return dict(entry, k=kc, v=vc)
 
     def commit(spec, entry, stg, stacked):
+        # a stacked entry lands every layer's window in one scatter
+        layer = jnp.arange(plan.n_rep) if stacked else None
         sink, recent = cache_window(cfg, spec)
         if sink or recent:
-            fn = lambda e, s: commit_ring(e, s, sink, recent)
-        else:
-            fn = commit_full
-        return jax.vmap(fn)(entry, stg) if stacked else fn(entry, stg)
+            return commit_ring(entry, stg, layer, sink, recent)
+        return commit_full(entry, stg, layer)
 
     per = tuple(commit(s, caches["period"][i], staged["period"][i], True)
                 for i, s in enumerate(plan.period))

@@ -292,7 +292,8 @@ class DecodeEngine:
             out["kmin"], out["kmax"], out["kmean"] = \
                 attn_mod.update_block_summaries(
                     entry["kmin"], entry["kmax"], entry["kmean"], out["k"],
-                    wtbl, stacked=stacked)
+                    wtbl, layer=(jnp.arange(entry["k"].shape[0])
+                                 if stacked else None))
         return out
 
     def _insert_attn_quant(self, entry, one, wtbl, stacked):
@@ -336,8 +337,8 @@ class DecodeEngine:
         out["kmin"], out["kmax"], out["kmean"] = \
             attn_mod.update_block_summaries(
                 entry["kmin"], entry["kmax"], entry["kmean"], out["k"],
-                wtbl, stacked=stacked, k_scale=out["kscale"],
-                k_tok=out["ktok"])
+                wtbl, k_scale=out["kscale"], k_tok=out["ktok"],
+                layer=jnp.arange(entry["k"].shape[0]) if stacked else None)
         return out
 
     def _extract_attn_paged(self, win, entry, slot, tbl, stacked):
@@ -541,8 +542,8 @@ class DecodeEngine:
         n_emit = jnp.where(act, a + 1, 0)
         emit = jnp.concatenate([nxt0[:, None], greedy[:, 1:]], axis=1)
         new_tok = jnp.where(act, emit[jnp.arange(B), a], state["tok"])
-        new_cache = self.lm.verify_commit(cache, staged, state["pos"],
-                                          n_emit, block_tbl)
+        new_cache = self.lm.verify_commit(staged, state["pos"], n_emit,
+                                          block_tbl)
         new_state = dict(state)
         new_state.update(pos=state["pos"] + n_emit, tok=new_tok)
         if "moe_counts" in state:

@@ -368,6 +368,74 @@ def test_audit_one_device_server(tiny):
                    for n in rep.audited)
         assert rep.checks.get("donation", 0) >= 1
         assert rep.checks.get("purity", 0) >= 1
+        assert rep.checks.get("arena-copy", 0) >= 1
+
+
+@pytest.mark.jaxpr_audit
+@pytest.mark.parametrize("plane", ["bf16", "int8", "spec"])
+def test_audit_arena_copy_four_layer_server(tiny, plane):
+    """Four layers scanned as one period repeated four times: the decode
+    step (or verify) and the paged prefill chunk update the stacked arenas
+    in place — no whole-stack or whole-layer copy, broadcast, slice or
+    slice update of any arena leaf in the compiled programs."""
+    from repro.serving import Server, ServerConfig
+    from repro.serving.quant import QuantConfig
+    from repro.serving.spec import SpecConfig
+    cfg, reqs = tiny
+    cfg = cfg.with_updates(n_layers=4)
+    kw = {"int8": {"quant": QuantConfig()}, "spec": {"spec": SpecConfig()},
+          "bf16": {}}[plane]
+    srv = Server(cfg, ServerConfig(decode_slots=4, max_len=96, **kw),
+                 pattern=[0] * 4)
+    assert srv.lm.plan.n_rep == 4
+    rng = np.random.default_rng(1)
+    srv.run(reqs + [(tuple(rng.integers(0, cfg.vocab_size, 40).tolist()),
+                     5)])
+    rep = srv.audit_hot_loops()
+    assert rep.ok(), rep.format()
+    audited = {n.split(".")[-1] for n in rep.audited}
+    step = "_verify_impl" if plane == "spec" else "_step_impl"
+    assert {step, "_resume_paged_impl"} <= audited, rep.format()
+    assert rep.checks.get("arena-copy", 0) >= 2
+
+
+@pytest.mark.jaxpr_audit
+def test_audit_catches_arena_scanned_as_xs_ys(tiny):
+    """Negative control: a layer scan that takes a stacked arena as xs and
+    returns it as ys (each layer sliced out, written, and stacked back into
+    a fresh buffer) is flagged by arena-copy; the same update with the
+    arena in the carry, indexed by the layer counter, passes."""
+    import jax.numpy as jnp
+    from repro.analysis.jaxpr_audit import audit_placement
+    from repro.serving import DevicePlacement
+    pl = DevicePlacement.local()
+    R, N, K, bs, h = 4, 9, 2, 4, 8
+
+    def xs_ys(cache, blk, rows):
+        def body(c, xs):
+            k, row = xs
+            return c, k.at[blk, :, 0].set(row)
+        _, k = jax.lax.scan(body, 0, (cache["period"][0]["k"], rows))
+        return {"period": ({"k": k},)}
+
+    def carried(cache, blk, rows):
+        def body(k, xs):
+            i, row = xs
+            return k.at[i, blk, :, 0].set(row), None
+        k, _ = jax.lax.scan(body, cache["period"][0]["k"],
+                            (jnp.arange(R), rows))
+        return {"period": ({"k": k},)}
+
+    # the registry holds entries weakly: keep the jits alive to audit them
+    jits = [pl.donate_jit(f, donate_argnums=(0,)) for f in (xs_ys, carried)]
+    for f in jits:
+        f({"period": ({"k": jnp.zeros((R, N, K, bs, h))},)}, jnp.int32(3),
+          jnp.ones((R, K, h)))
+    rep = audit_placement(pl)
+    flagged = {f.entry.split(".")[-1] for f in rep.findings
+               if f.check == "arena-copy"}
+    assert flagged == {"xs_ys"}, rep.format()
+    assert rep.checks.get("arena-copy") == 2
 
 
 @pytest.mark.jaxpr_audit
